@@ -7,6 +7,27 @@ suites consume, and re-exports the metric helpers from their new home in
 """
 from __future__ import annotations
 
+import json
+import os
+import subprocess
+import sys
+
+# the virtual-shard studies run on the host CPU backend by design: each
+# child gets JAX_PLATFORMS=cpu, so none of them contends for an accelerator
+# that the parent (or another process) holds
+CPU_CHILD_NOTE = "virtual CPU shards in a JAX_PLATFORMS=cpu child"
+
+
+def run_cpu_child(src: str, *, timeout: int = 900) -> dict:
+    """Run ``python -c src`` on the CPU backend and return the JSON object
+    it prints on its last stdout line."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "-c", src], capture_output=True,
+                       text=True, timeout=timeout, cwd=".", env=env)
+    if r.returncode != 0:
+        raise RuntimeError(r.stderr[-2000:])
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
 
 def run_crawl(cfg, steps, *, classify_accuracy=0.9, mesh=None,
               events=None, mode="auto"):

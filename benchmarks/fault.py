@@ -4,10 +4,9 @@ Runs on 4 virtual shards in a subprocess.
 """
 from __future__ import annotations
 
-import json
-import subprocess
-import sys
 import textwrap
+
+from benchmarks.crawl_common import CPU_CHILD_NOTE, run_cpu_child
 
 CHILD = textwrap.dedent("""
     import os, sys, json
@@ -41,15 +40,12 @@ CHILD = textwrap.dedent("""
 
 
 def run(fail, heal):
-    r = subprocess.run([sys.executable, "-c", CHILD % dict(fail=fail, heal=heal)],
-                       capture_output=True, text=True, timeout=900, cwd=".")
-    if r.returncode != 0:
-        raise RuntimeError(r.stderr[-2000:])
-    return json.loads(r.stdout.strip().splitlines()[-1])
+    return run_cpu_child(CHILD % dict(fail=fail, heal=heal))
 
 
 def main():
-    print("\n== C4: shard failure at step 16 (4 shards, 48 steps) ==")
+    print(f"\n== C4: shard failure at step 16 (4 {CPU_CHILD_NOTE}, "
+          f"48 steps) ==")
     base = run(-1, -1)
     dead = run(16, -1)
     healed = run(16, 28)

@@ -18,10 +18,10 @@ SUITES entry).
 """
 from __future__ import annotations
 
-import json
-import subprocess
 import sys
 import textwrap
+
+from benchmarks.crawl_common import CPU_CHILD_NOTE, run_cpu_child
 
 CHILD = textwrap.dedent("""
     import os, sys, json, importlib
@@ -59,11 +59,7 @@ def point(scheme: str, acc: float, *, coord: str = "exchange",
           quota: int = -1, steps: int = 64, cfg_kw=None) -> dict:
     src = CHILD % dict(scheme=scheme, acc=acc, coord=coord, quota=quota,
                        steps=steps, cfg_kw=cfg_kw or FULL_CFG)
-    r = subprocess.run([sys.executable, "-c", src], capture_output=True,
-                       text=True, timeout=900, cwd=".")
-    if r.returncode != 0:
-        raise RuntimeError(r.stderr[-2000:])
-    return json.loads(r.stdout.strip().splitlines()[-1])
+    return run_cpu_child(src)
 
 
 def _row(label1, label2, rec):
@@ -105,7 +101,7 @@ def main(smoke: bool = False):
                          point(scheme, 0.9, coord=coord, quota=q,
                                steps=steps, cfg_kw=cfg_kw)))
     print(f"\n== coordination mode x partitioning: overlap / coverage / "
-          f"quality / bandwidth (8 shards, {steps} steps, "
+          f"quality / bandwidth (8 {CPU_CHILD_NOTE}, {steps} steps, "
           f"batched quota={quota}) ==")
     print(_HDR)
     for coord, scheme, rec in rows:
@@ -135,7 +131,7 @@ def main(smoke: bool = False):
                                        cfg_kw=cfg_kw))
                 for acc in (1.0, 0.7, 0.5)]
     print("\n== C1/C2: overlap by classifier accuracy "
-          f"(webparf/exchange, 8 shards, {steps} steps) ==")
+          f"(webparf/exchange, 8 {CPU_CHILD_NOTE}, {steps} steps) ==")
     print(_HDR)
     for scheme, acc, rec in acc_rows:
         _row(scheme, f"acc={acc:.2f}", rec)

@@ -24,12 +24,12 @@ benchmarks/run.py).
 """
 from __future__ import annotations
 
-import json
-import subprocess
 import sys
 import textwrap
 
 import numpy as np
+
+from benchmarks.crawl_common import CPU_CHILD_NOTE, run_cpu_child
 
 CHILD = textwrap.dedent("""
     import os, sys, json
@@ -78,11 +78,7 @@ def point(*, thr: float, steps: int, cfg_kw: dict, zipf: float = 1.35,
           loc: float = 0.5, maxd: int = 6) -> dict:
     src = CHILD % dict(thr=thr, steps=steps, cfg_kw=cfg_kw, zipf=zipf,
                        loc=loc, maxd=maxd)
-    r = subprocess.run([sys.executable, "-c", src], capture_output=True,
-                       text=True, timeout=900, cwd=".")
-    if r.returncode != 0:
-        raise RuntimeError(r.stderr[-2000:])
-    rec = json.loads(r.stdout.strip().splitlines()[-1])
+    rec = run_cpu_child(src)
     series = rec["imb_series"]
     rec["imb_max_raw"] = max(series)
     rec["imb_max"] = max(series[WARMUP:] or series)
@@ -112,7 +108,7 @@ def main(smoke: bool = False):
     elastic = point(thr=thr, steps=steps, cfg_kw=cfg_kw)
 
     print(f"\n== elastic repartitioning on a Zipf-skewed web "
-          f"(4 shards, {steps} steps, trigger threshold {thr}) ==")
+          f"(4 {CPU_CHILD_NOTE}, {steps} steps, trigger threshold {thr}) ==")
     print(_HDR)
     _row("static", static)
     _row("elastic", elastic)

@@ -1,9 +1,12 @@
 """Roofline analysis (deliverable g) — three terms per (arch x shape) cell
 from the dry-run artifacts in benchmarks/results/dryrun/<mesh>/.
 
-    compute term    = HLO_FLOPs_per_dev / peak_FLOP/s      (197 TF bf16, v5e)
-    memory term     = HLO_bytes_per_dev / HBM_bw           (819 GB/s)
-    collective term = collective_bytes_per_dev / link_bw   (~50 GB/s ICI)
+    compute term    = HLO_FLOPs_per_dev / peak_FLOP/s
+    memory term     = HLO_bytes_per_dev / HBM_bw
+    collective term = collective_bytes_per_dev / ICI_bw
+
+with the peaks of the production target chip from repro.launch.mesh.PEAKS
+(keyed by device kind, with their published source).
 
 HLO_FLOPs / bytes / collective bytes come from benchmarks/hlo_analysis.py
 (per-partition program, loop trip counts applied). MODEL_FLOPS is the 6ND /
@@ -16,7 +19,12 @@ import json
 import pathlib
 from typing import Optional
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import TARGET_KIND, chip_peaks
+
+_PEAK = chip_peaks(TARGET_KIND)
+PEAK_FLOPS_BF16 = _PEAK["flops_bf16"]
+HBM_BW = _PEAK["hbm_bw"]
+ICI_BW = _PEAK["ici_bw"]
 
 
 def analytic_model_flops(arch: str, shape_name: str) -> Optional[float]:
@@ -191,8 +199,9 @@ def main(results_dir: str = "benchmarks/results/dryrun", mesh: str = "single"):
               "--all --mesh single` first)")
         return rows
 
-    print(f"\n== Roofline, {mesh} pod ({rows[0]['chips']} chips, TPU v5e "
-          f"constants) — times are per-step lower bounds ==")
+    print(f"\n== Roofline, {mesh} pod ({rows[0]['chips']} chips, "
+          f"{TARGET_KIND} published peaks) — times are per-step lower "
+          f"bounds ==")
     hdr = (f"{'arch':22s} {'shape':14s} {'compute':>8s} {'mem floor':>9s} "
            f"{'mem xla':>8s} {'collect':>8s} {'bound':>10s} "
            f"{'useful':>7s} {'roofline%':>9s}")

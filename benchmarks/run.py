@@ -7,7 +7,7 @@ report from the dry-run artifacts.
 
 A suite whose ``main`` returns a dict gets that dict persisted as
 ``BENCH_<suite>.json`` next to this file's repo root — the mechanism behind
-the committed perf trajectories (currently ``BENCH_dispatch.json``).
+the committed perf trajectories (the ``BENCH_*.json`` files).
 """
 from __future__ import annotations
 
@@ -25,6 +25,8 @@ ROOT = pathlib.Path(__file__).resolve().parent.parent
 
 
 def main(argv=None) -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     args = (argv if argv is not None else sys.argv[1:]) or list(SUITES)
     failures = []
     for name in args:

@@ -5,10 +5,9 @@ own --xla_force_host_platform_device_count.
 """
 from __future__ import annotations
 
-import json
-import subprocess
-import sys
 import textwrap
+
+from benchmarks.crawl_common import CPU_CHILD_NOTE, run_cpu_child
 
 CHILD = textwrap.dedent("""
     import os, sys, json
@@ -29,15 +28,12 @@ CHILD = textwrap.dedent("""
 
 def point(n_shards, n_domains, fetch_batch):
     src = CHILD % (n_shards, n_domains, fetch_batch, n_shards)
-    r = subprocess.run([sys.executable, "-c", src], capture_output=True,
-                       text=True, timeout=900, cwd=".")
-    if r.returncode != 0:
-        raise RuntimeError(r.stderr[-2000:])
-    return json.loads(r.stdout.strip().splitlines()[-1])
+    return run_cpu_child(src)
 
 
 def main():
-    print("\n== C3: crawl throughput vs parallel crawl processes ==")
+    print(f"\n== C3: crawl throughput vs parallel crawl processes "
+          f"({CPU_CHILD_NOTE}) ==")
     print(f"{'shards':>7s} {'domains':>8s} {'fetched(32 steps)':>18s} "
           f"{'steady pages/step':>18s}")
     base = None

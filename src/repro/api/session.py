@@ -37,14 +37,15 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.api.report import CrawlReport, harvest, stats_dict, stats_per_shard
 from repro.compat import shard_map
 from repro.configs.base import CrawlConfig
 from repro.core import classifier as CLS
 from repro.core import crawler as CR
-from repro.core.stages import CrawlState, FetchReport, state_specs
+from repro.core.stages import (CrawlState, FetchReport, init_state,
+                               state_specs)
 
 Events = Dict[int, Callable]   # step index -> state transform, applied BEFORE
                                # that step executes (session-absolute indices)
@@ -84,9 +85,9 @@ class CrawlSession:
             self._kw["extra_stages"] = tuple(extra_stages)
         if dispatch_stage is not None:
             self._kw["dispatch_stage"] = dispatch_stage
-        init, self._step_f, self._step_d = CR.make_spmd_crawler(
+        self._init, self._step_f, self._step_d = CR.make_spmd_crawler(
             cfg, self.mesh, axes=self.axes, **self._kw)
-        self.state: CrawlState = init()
+        self.state: CrawlState = self._init()
         self._t = 0
         self._chunk_fn = None          # built lazily on first scan use
         # -- observability (DESIGN.md §17); off -> all hooks are dead code on
@@ -126,8 +127,7 @@ class CrawlSession:
         functions — cheap repeated trajectories for sweeps and property
         tests (tests/test_invariants.py drives hundreds of schedules
         through one session per config)."""
-        from repro.core.stages import init_state
-        self.state = init_state(self.cfg, self.n_shards)
+        self.state = self._init()
         self._t = 0
         self.rebalance_events = []
         if self.telemetry:
@@ -170,7 +170,9 @@ class CrawlSession:
                 f"run_chunk: step counter t={self._t} is not aligned to "
                 f"dispatch_interval={iv}; use .step() to reach a boundary")
         if self._chunk_fn is None:
-            self._chunk_fn = self._build_chunk()
+            self._chunk_fn = chunk_program(
+                self.cfg, self.mesh, axes=self.axes,
+                telemetry=self.telemetry, **self._kw)
         if not self.telemetry:
             self.state, reps = self._chunk_fn(self.state)
             self._t += iv
@@ -220,57 +222,6 @@ class CrawlSession:
                               names=self.ledger.names,
                               interval=self.cfg.dispatch_interval,
                               spans=tuple(self.tracer.events))
-
-    def _build_chunk(self):
-        """One jitted shard_map whose body scans the whole interval. With
-        telemetry on, each scanned step also emits its ledger row — an extra
-        stacked ``(iv, 1, n_metrics)`` output per shard (global
-        ``(iv, n_shards, n_metrics)``), never a host callback. The snapshot
-        only READS state, so the crawl trajectory is bit-identical either
-        way (tests/test_obs.py pins it)."""
-        cfg, axes = self.cfg, self.axes
-        local = CR.make_crawl_step(cfg, n_shards=self.n_shards, axes=axes,
-                                   **self._kw)
-        specs = state_specs(axes)
-        # stacked reports grow a leading (unsharded) time axis
-        rep_specs = FetchReport(P(None, axes), P(None, axes))
-        iv = cfg.dispatch_interval
-
-        if self.telemetry:
-            from repro.obs import ledger as OL
-
-            def chunk_local(state):
-                def body(st, _):
-                    st, rep = local(st, dispatch=False)
-                    return st, (rep, OL.snapshot_local(cfg, axes, st,
-                                                       dispatch=False))
-                state, (reps, rows) = lax.scan(body, state, None,
-                                               length=iv - 1)
-                state, rep_d = local(state, dispatch=True)
-                reps = jax.tree.map(
-                    lambda a, b: jnp.concatenate([a, b[None]], 0),
-                    reps, rep_d)
-                rows = jnp.concatenate(
-                    [rows, OL.snapshot_local(cfg, axes, state,
-                                             dispatch=True)[None]], 0)
-                return state, reps, rows
-
-            return jax.jit(shard_map(chunk_local, mesh=self.mesh,
-                                     in_specs=(specs,),
-                                     out_specs=(specs, rep_specs,
-                                                P(None, axes))))
-
-        def chunk_local(state):
-            state, reps = lax.scan(lambda st, _: local(st, dispatch=False),
-                                   state, None, length=iv - 1)
-            state, rep_d = local(state, dispatch=True)
-            reps = jax.tree.map(lambda a, b: jnp.concatenate([a, b[None]], 0),
-                                reps, rep_d)
-            return state, reps
-
-        return jax.jit(shard_map(chunk_local, mesh=self.mesh,
-                                 in_specs=(specs,),
-                                 out_specs=(specs, rep_specs)))
 
     # -- the driver loop ----------------------------------------------------
 
@@ -457,3 +408,71 @@ class CrawlSession:
             except FileNotFoundError:
                 self.ledger.clear()    # pre-telemetry checkpoint: start fresh
         return self
+
+
+def chunk_program(cfg: CrawlConfig, mesh, *, axes=("data",),
+                  telemetry: bool = False, **kw):
+    """One jitted shard_map whose body scans the whole interval. With
+    telemetry on, each scanned step also emits its ledger row — an extra
+    stacked ``(iv, 1, n_metrics)`` output per shard (global
+    ``(iv, n_shards, n_metrics)``), never a host callback. The snapshot
+    only READS state, so the crawl trajectory is bit-identical either
+    way (tests/test_obs.py pins it)."""
+    axes = axes if isinstance(axes, tuple) else (axes,)
+    n_shards = int(math.prod(mesh.shape[a] for a in axes))
+    local = CR.make_crawl_step(cfg, n_shards=n_shards, axes=axes, **kw)
+    specs = state_specs(axes)
+    # stacked reports grow a leading (unsharded) time axis
+    rep_specs = FetchReport(P(None, axes), P(None, axes))
+    iv = cfg.dispatch_interval
+
+    if telemetry:
+        from repro.obs import ledger as OL
+
+        def chunk_local(state):
+            def body(st, _):
+                st, rep = local(st, dispatch=False)
+                return st, (rep, OL.snapshot_local(cfg, axes, st,
+                                                   dispatch=False))
+            state, (reps, rows) = lax.scan(body, state, None,
+                                           length=iv - 1)
+            state, rep_d = local(state, dispatch=True)
+            reps = jax.tree.map(
+                lambda a, b: jnp.concatenate([a, b[None]], 0),
+                reps, rep_d)
+            rows = jnp.concatenate(
+                [rows, OL.snapshot_local(cfg, axes, state,
+                                         dispatch=True)[None]], 0)
+            return state, reps, rows
+
+        return jax.jit(shard_map(chunk_local, mesh=mesh,
+                                 in_specs=(specs,),
+                                 out_specs=(specs, rep_specs,
+                                            P(None, axes))))
+
+    def chunk_local(state):
+        state, reps = lax.scan(lambda st, _: local(st, dispatch=False),
+                               state, None, length=iv - 1)
+        state, rep_d = local(state, dispatch=True)
+        reps = jax.tree.map(lambda a, b: jnp.concatenate([a, b[None]], 0),
+                            reps, rep_d)
+        return state, reps
+
+    return jax.jit(shard_map(chunk_local, mesh=mesh,
+                             in_specs=(specs,),
+                             out_specs=(specs, rep_specs)))
+
+
+def chunk_memory(cfg: CrawlConfig, mesh, *, axes=("data",)):
+    """``memory_analysis()`` of the compiled fused chunk for ``cfg`` on
+    ``mesh`` (per device), from shapes alone: nothing is allocated, so it
+    can size a configuration that would not fit."""
+    axes = axes if isinstance(axes, tuple) else (axes,)
+    n_shards = int(math.prod(mesh.shape[a] for a in axes))
+    shapes = jax.eval_shape(lambda: init_state(cfg, n_shards))
+    args = jax.tree.map(
+        lambda s, p: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                          sharding=NamedSharding(mesh, p)),
+        shapes, state_specs(axes))
+    return chunk_program(cfg, mesh, axes=axes).lower(args).compile(
+        ).memory_analysis()

@@ -1,84 +1,21 @@
-"""jax version-compatibility shims (single import point for moving APIs).
-
-The repo targets whatever jax the image bakes in; three APIs moved between
-jax 0.4.x and 0.5+:
-
-  * ``jax.sharding.AxisType`` / ``jax.make_mesh(..., axis_types=...)`` —
-    absent on 0.4.x. ``make_mesh`` here feature-detects and only passes
-    ``axis_types`` when the running jax understands it (the crawler and the
-    dry-run only ever want Auto axes anyway).
-  * ``jax.shard_map`` — lives at ``jax.experimental.shard_map.shard_map`` on
-    0.4.x, where the replication-check kwarg is ``check_rep`` rather than
-    ``check_vma``.
-  * ``lax.optimization_barrier`` has no differentiation rule on 0.4.x.
-    ``opt_barrier`` wraps it in a custom_jvp (identity on the tangent — the
-    barrier exists to pin the primal's scheduling; under remat the recomputed
-    forward keeps it), so grad works instead of crashing.
-"""
+"""Mesh and shard_map helpers shared by the crawler, the serve path and the
+model cells (single import point, so every caller builds meshes and
+shard_maps the same way)."""
 from __future__ import annotations
 
 from typing import Sequence
 
 import jax
-import jax.numpy as jnp
-from jax import lax
-
-HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               **kw) -> jax.sharding.Mesh:
-    """jax.make_mesh with every axis Auto, on any supported jax."""
-    if HAS_AXIS_TYPES:
-        kw.setdefault("axis_types",
-                      (jax.sharding.AxisType.Auto,) * len(axis_names))
+    """jax.make_mesh with every axis Auto."""
+    kw.setdefault("axis_types", (jax.sharding.AxisType.Auto,) * len(axis_names))
     return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)
 
 
-if hasattr(jax, "shard_map"):
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return _shard_map_exp(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
-
-
-def _barrier_differentiable() -> bool:
-    try:
-        jax.jvp(lax.optimization_barrier, (jnp.float32(0.0),),
-                (jnp.float32(0.0),))
-        return True
-    except NotImplementedError:
-        return False
-
-
-if _barrier_differentiable():
-    # this jax ships a differentiation rule — use the primitive directly so
-    # EVERY leaf (including scanned integer indices) stays barriered
-    def opt_barrier(x):
-        """Grad-safe ``lax.optimization_barrier`` over an arbitrary pytree."""
-        return lax.optimization_barrier(x)
-else:
-    from functools import partial as _partial
-
-    @jax.custom_jvp
-    def _opt_barrier(x):
-        return lax.optimization_barrier(x)
-
-    @_partial(_opt_barrier.defjvp, symbolic_zeros=True)
-    def _opt_barrier_jvp(primals, tangents):
-        # identity on tangents (symbolic zeros pass through untouched, so
-        # integer leaves never materialize float0s): the barrier exists to
-        # pin the PRIMAL's scheduling, and under remat the recomputed
-        # forward keeps it
-        (x,), (t,) = primals, tangents
-        return lax.optimization_barrier(x), t
-
-    def opt_barrier(x):
-        """Grad-safe ``lax.optimization_barrier`` over an arbitrary pytree
-        (custom-JVP shim: jax 0.4.x has no rule for the primitive)."""
-        return _opt_barrier(x)
+def shard_map(f, *, mesh, in_specs, out_specs):
+    """jax.shard_map without the replication (vma) check."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

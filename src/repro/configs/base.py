@@ -203,7 +203,10 @@ class CrawlConfig:
     fetch_batch: int = 64             # URLs fetched per shard per step (downloader width)
     outlinks_per_page: int = 16       # parser yield per page
     n_priority_buckets: int = 8       # prioritized-queue levels (Fig. 5)
-    bloom_bits_log2: int = 24         # per-shard Bloom filter: 2^24 bits = 2 MiB
+    bloom_bits_log2: int = 24         # Bloom filter per frontier row, byte
+                                      # per bit: 2^24 -> 16 MiB per row, 8 GiB
+                                      # for the default 512 rows (one v5e
+                                      # chip fits 2^23: chip_smoke.py)
     bloom_hashes: int = 4
     dispatch_interval: int = 4        # steps between batched URL exchanges (C5)
     dispatch_capacity: int = 2048     # max URLs exchanged per shard per dispatch
@@ -241,8 +244,11 @@ class CrawlConfig:
                                       # C4 rebalancing never merges queues)
     kernel_impl: str = "auto"         # frontier-select/bloom implementation:
                                       # "ref" | "pallas" | "interpret" | "auto"
-                                      # (auto = Pallas on TPU, ref elsewhere;
-                                      # resolved by kernels/registry.py)
+                                      # (auto = each family's registered TPU
+                                      # default on TPU — Pallas for the
+                                      # select families, ref for the rest —
+                                      # and ref elsewhere; resolved by
+                                      # kernels/registry.py)
     telemetry: bool = False           # observability layer (DESIGN.md §17):
                                       # collect the per-shard, per-step load
                                       # ledger inside the step/scan (extra

@@ -43,7 +43,7 @@ from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.compat import shard_map
 from repro.configs.base import CrawlConfig
@@ -209,6 +209,16 @@ def make_spmd_crawler(cfg: CrawlConfig, mesh, axes=("data",),
             in_specs=(specs,), out_specs=(specs, rep_specs))
         return fn(state)
 
+    # the initial state is built straight into its shardings: the row leaves
+    # land on their own shards, and each shard seeds its own Bloom rows
+    local_bloom = shard_map(partial(ST.seed_bloom, cfg), mesh=mesh,
+                            in_specs=(P(axes_t), P(axes_t)),
+                            out_specs=P(axes_t))
+    init = jax.jit(
+        lambda: init_state(cfg, n_shards,
+                           bloom=lambda _, url, valid: local_bloom(url, valid)),
+        out_shardings=jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                                   is_leaf=lambda s: isinstance(s, P)))
     step_fetch = jax.jit(partial(step, dispatch=False))
     step_dispatch = jax.jit(partial(step, dispatch=True))
-    return partial(init_state, cfg, n_shards), step_fetch, step_dispatch
+    return init, step_fetch, step_dispatch

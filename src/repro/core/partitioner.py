@@ -64,7 +64,11 @@ def seed_frontier(cfg: CrawlConfig, n_shards: int) -> F.Frontier:
     """Gather hub seeds per domain (the classification-hierarchy method) and
     build the initial prioritized queues at each domain's slot."""
     dm = identity_map(cfg, n_shards)
-    f = F.init_frontier(cfg.n_slots, cfg.frontier_capacity)
+    # the barrier keeps the compiler from constant-folding the insert's
+    # FIFO-rebase sorts over the all-constant empty frontier, which takes
+    # minutes at deployment sizes
+    f = jax.lax.optimization_barrier(
+        F.init_frontier(cfg.n_slots, cfg.frontier_capacity))
     seeds = W.hub_seeds(cfg)                              # (n_domains, N)
     # the candidate window can hash-collide: dedup per domain or the same
     # seed URL is queued (and crawled) twice — C1 leak #2 found by

@@ -224,24 +224,38 @@ def apply_delta(state: CrawlState, delta: StatsDelta) -> CrawlState:
     return state._replace(stats=stats)
 
 
-def init_state(cfg: CrawlConfig, n_shards: int) -> CrawlState:
+def seed_bloom(cfg: CrawlConfig, f_url: jax.Array, f_valid: jax.Array
+               ) -> jax.Array:
+    """Fresh Bloom rows with the queued seed URLs registered. Without this a
+    seed URL re-discovered via an outlink is re-inserted and crawled TWICE
+    (the one C1 leak found by benchmarks/overlap.py at
+    classify_accuracy=1.0). Row-local, so a sharded init builds each
+    shard's filter rows on that shard."""
+    bloom = DD.init_bloom(f_url.shape[0], cfg.bloom_bits_log2)
+    _, bloom = DD.probe_insert(bloom, f_url, f_valid, k=cfg.bloom_hashes,
+                               impl=cfg.kernel_impl)
+    return bloom.bits
+
+
+def init_state(cfg: CrawlConfig, n_shards: int, *,
+               bloom: Callable = seed_bloom) -> CrawlState:
+    """The initial crawl state. ``bloom`` builds the filter rows from the
+    seeded frontier (``crawler.make_spmd_crawler`` passes a shard_mapped
+    ``seed_bloom`` so no device ever holds the whole filter)."""
     assert cfg.n_domains % n_shards == 0, (cfg.n_domains, n_shards)
     assert cfg.n_slots % n_shards == 0
     f = PT.seed_frontier(cfg, n_shards)
     dm = PT.identity_map(cfg, n_shards)
-    # register the seeds in the Bloom filters: without this a seed URL
-    # re-discovered via an outlink is re-inserted and crawled TWICE (the one
-    # C1 leak found by benchmarks/overlap.py at classify_accuracy=1.0)
-    bloom = DD.init_bloom(cfg.n_slots, cfg.bloom_bits_log2)
-    _, bloom = DD.probe_insert(bloom, f.url, f.valid, k=cfg.bloom_hashes,
-                               impl=cfg.kernel_impl)
+    # the seeds fill the first columns of each row (an insert takes free
+    # slots in column order), so those columns hold every valid cell
+    n0 = min(cfg.seed_urls_per_domain, cfg.frontier_capacity)
     S = cfg.dispatch_capacity
     from repro.coordination.outbox import init_outbox
     from repro.ordering.policies import get_ordering
     return CrawlState(
         f_url=f.url, f_pri=f.priority, f_valid=f.valid, f_arrival=f.arrival,
         f_dropped=f.n_dropped, f_inserted=f.n_inserted, f_rebased=f.n_rebased,
-        bloom_bits=bloom.bits,
+        bloom_bits=bloom(cfg, f.url[:, :n0], f.valid[:, :n0]),
         slot_domain=dm.domain_of_slot,
         order_state=get_ordering(cfg.ordering).init_state(cfg, n_shards),
         staging_url=jnp.zeros((n_shards, S), jnp.uint32),

@@ -10,8 +10,9 @@ Layout: bits are byte-per-bit uint8 (matching core/dedup.py state). A packed
 uint32 variant (8x VMEM density) is the §Perf follow-up noted in
 EXPERIMENTS.md. Probe indices are mod-2^b so index arithmetic is shift/mask.
 
-Validated with interpret=True; the dynamic gather/scatter inside the kernel
-body targets Mosaic's VMEM dynamic-indexing path on real TPU.
+Validated with interpret=True only: Mosaic refuses both variants for a v5e
+(the (1, N) blocks break its (8, 128) block rule, and the VMEM gather/
+scatter has no TPU lowering), so ops.py registers ref as the TPU default.
 """
 from __future__ import annotations
 

@@ -15,10 +15,13 @@ from repro.kernels import registry
 from repro.kernels.bloom.bloom import bloom_probe_insert
 from repro.kernels.bloom.ref import bloom_ref
 
-registry.register("bloom", "ref", bloom_ref, cpu_default=True)
-registry.register("bloom", "pallas",
-                  partial(bloom_probe_insert, interpret=False),
+# ref is the TPU default too: Mosaic refuses the compiled kernel (a (1, 2^b)
+# row block breaks the (8, 128) block rule, and the VMEM gather/scatter has
+# no TPU lowering)
+registry.register("bloom", "ref", bloom_ref, cpu_default=True,
                   tpu_default=True)
+registry.register("bloom", "pallas",
+                  partial(bloom_probe_insert, interpret=False))
 registry.register("bloom", "interpret",
                   partial(bloom_probe_insert, interpret=True))
 

@@ -31,8 +31,9 @@ variant absorbed into this family; cf. kernels/bloom's standalone packed
 kernel): ops.py registers it as the ``pallas_packed`` / ``interpret_packed``
 implementations, packing at the XLA boundary.
 
-Validated with interpret=True on CPU; the dynamic gather/scatter targets
-Mosaic's VMEM dynamic-indexing path on real TPU.
+Validated with interpret=True on CPU only: Mosaic refuses it for a v5e (the
+(1, N) blocks break its (8, 128) block rule, and scatter-add has no TPU
+lowering), so ops.py registers ref as the TPU default.
 """
 from __future__ import annotations
 

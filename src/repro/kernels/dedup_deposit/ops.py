@@ -32,11 +32,13 @@ def _packed(interpret: bool):
     return run
 
 
+# ref is the TPU default too: Mosaic refuses the compiled kernel (a (1, M)
+# tile block breaks the (8, 128) block rule, and scatter-add has no TPU
+# lowering)
 registry.register("dedup_deposit", "ref", dedup_deposit_ref,
-                  cpu_default=True)
+                  cpu_default=True, tpu_default=True)
 registry.register("dedup_deposit", "pallas",
-                  partial(dedup_deposit_kernel, interpret=False),
-                  tpu_default=True)
+                  partial(dedup_deposit_kernel, interpret=False))
 registry.register("dedup_deposit", "interpret",
                   partial(dedup_deposit_kernel, interpret=True))
 registry.register("dedup_deposit", "pallas_packed", _packed(interpret=False))

@@ -11,6 +11,7 @@ f32 accumulation order, which is what makes ref <-> interpret bit-identity
 testable on adversarial inputs too.
 """
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core.dedup import probe_insert_arrays
 
@@ -23,13 +24,13 @@ def dedup_deposit_ref(bits, urls, mask, val, f_url, f_valid, table, *,
     R, M = urls.shape
     C = f_url.shape[1]
     url_tile = min(url_tile, M)
+    assert M % url_tile == 0, (M, url_tile)
     rows = jnp.arange(R)[:, None]
-    seen_parts = []
-    refund = jnp.zeros((R,), jnp.float32)
-    for t0 in range(0, M, url_tile):
-        u = urls[:, t0:t0 + url_tile]
-        m = mask[:, t0:t0 + url_tile]
-        v = val[:, t0:t0 + url_tile]
+
+    def walk(t, carry):
+        seen, bits, table, refund = carry
+        u, m, v = (lax.dynamic_slice_in_dim(a, t * url_tile, url_tile, axis=1)
+                   for a in (urls, mask, val))
         s, bits = probe_insert_arrays(bits, u, m, k=k, bits_log2=bits_log2)
         twin = (u[:, :, None] == f_url[:, None, :]) \
             & f_valid[:, None, :] & s[:, :, None]        # (R, tile, C)
@@ -38,6 +39,11 @@ def dedup_deposit_ref(bits, urls, mask, val, f_url, f_valid, table, *,
         table = table.at[rows, jnp.where(hit, cell, C)].add(
             jnp.where(hit, v, 0.0), mode="drop")
         refund = refund + jnp.where(s & ~hit, v, 0.0).sum(axis=1)
-        seen_parts.append(s)
-    return (jnp.concatenate(seen_parts, axis=1), bits, table,
-            refund[:, None])
+        seen = lax.dynamic_update_slice_in_dim(seen, s, t * url_tile, axis=1)
+        return seen, bits, table, refund
+
+    seen, bits, table, refund = lax.fori_loop(
+        0, M // url_tile, walk,
+        (jnp.zeros((R, M), jnp.bool_), bits, table,
+         jnp.zeros((R,), jnp.float32)))
+    return seen, bits, table, refund[:, None]

@@ -10,8 +10,9 @@ per element. The grid walks contribution tiles sequentially per batch row,
 so duplicate-row accumulation order is deterministic — ref.py replays the
 same tile walk, which is what the bit-identity tests pin down.
 
-Validated with interpret=True on CPU; the dynamic scatter targets Mosaic's
-VMEM dynamic-indexing path on real TPU.
+Validated with interpret=True on CPU only: Mosaic refuses it for a v5e
+(scatter-add has no TPU lowering), so ops.py registers ref as the TPU
+default.
 """
 from __future__ import annotations
 

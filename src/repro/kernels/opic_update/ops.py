@@ -15,9 +15,12 @@ from repro.kernels import registry
 from repro.kernels.opic_update.opic_update import opic_scatter_add
 from repro.kernels.opic_update.ref import opic_ref
 
-registry.register("opic_update", "ref", opic_ref, cpu_default=True)
+# ref is the TPU default too: Mosaic refuses the compiled kernel (scatter-add
+# has no TPU lowering)
+registry.register("opic_update", "ref", opic_ref, cpu_default=True,
+                  tpu_default=True)
 registry.register("opic_update", "pallas",
-                  partial(opic_scatter_add, interpret=False), tpu_default=True)
+                  partial(opic_scatter_add, interpret=False))
 registry.register("opic_update", "interpret",
                   partial(opic_scatter_add, interpret=True))
 

@@ -81,6 +81,8 @@ def main(argv=None):
                     help="write the Chrome trace_event file (.json or "
                          ".jsonl) with the ledger embedded; implies --trace")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     trace = args.trace or bool(args.trace_out) or \
         args.rebalance_threshold > 0
 

@@ -62,6 +62,8 @@ def main(argv=None):
                     help="write the Chrome trace_event file (.json or "
                          ".jsonl); implies --trace")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     trace = args.trace or bool(args.trace_out)
 
     cfg = scaled(get_arch("webparf")[0], n_domains=args.domains,

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import opt_barrier, shard_map
+from repro.compat import shard_map
 from repro.configs.base import LMConfig, MoEConfig
 from repro.sharding.rules import constrain
 
@@ -105,7 +105,7 @@ def chunked_attention(
             # barrier: stop XLA loop-invariant code motion from materializing
             # every iteration's mask/score block outside the scan (observed
             # 3.2 GB hoisted mask tensors on the train_4k baseline)
-            (kb, vb, kj) = opt_barrier((kb, vb, kj))
+            (kb, vb, kj) = lax.optimization_barrier((kb, vb, kj))
             s = _gqa_scores(qb, kb)                    # (B,Hkv,G,qc,kc) f32
             if causal:
                 kv_pos = kj * kv_chunk + jnp.arange(kv_chunk)
@@ -473,7 +473,7 @@ def chunked_softmax_xent(hidden: jax.Array, lm_head: jax.Array,
         h, l = xs
         # barrier: without it XLA hoists the (loop-invariant-looking) logits
         # matmul out of the scan and materializes ALL chunks' logits at once
-        h, l = opt_barrier((h, l))
+        h, l = lax.optimization_barrier((h, l))
         logits = (h @ lm_head).astype(jnp.float32)     # (B, chunk, V)
         logits = constrain(logits, "dp", None, "tp")
         logz = jax.nn.logsumexp(logits, axis=-1)

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import opt_barrier, shard_map
+from repro.compat import shard_map
 from repro.configs.base import RecSysConfig
 
 Params = Dict[str, Any]
@@ -125,7 +125,7 @@ def chunked_topk_scores(query: jax.Array, table: jax.Array, *, k: int = 100,
     def step(carry, xs):
         best_s, best_i = carry
         block, j = xs
-        block = opt_barrier(block)   # keep per-chunk (no hoist)
+        block = lax.optimization_barrier(block)   # keep per-chunk (no hoist)
         # replicate the 4 MB table block (NOT the 1 GiB score block): scores
         # inherit the table's model sharding otherwise, and the top-k concat
         # then all-gathers (B, chunk) every scan step

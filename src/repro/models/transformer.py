@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import opt_barrier
 from repro.configs.base import LMConfig
 from repro.models import layers as L
 from repro.sharding.rules import constrain
@@ -121,7 +120,7 @@ def forward(params: Params, cfg: LMConfig, tokens: jax.Array, *,
         # barrier: keep the remat stash consumed slice-wise in bf16 — without
         # it XLA hoists convert(slice(stash)) into a full f32 copy of the
         # (L, B, S, d) stash (observed +10.5 GiB on train_4k)
-        x = opt_barrier(x)
+        x = lax.optimization_barrier(x)
         x, aux = _layer_fwd(lp, cfg, x, positions, moe=is_moe,
                             n_groups=n_groups, causal_skip=causal_skip)
         x = constrain(x, "dp", None, None)

@@ -47,6 +47,17 @@ def test_registry_auto_resolves_per_backend():
     assert registry.resolve_impl("bloom", "interpret") == "interpret"
 
 
+@pytest.mark.parametrize("kern,want", [
+    ("frontier_select", "pallas"), ("select_harvest", "pallas"),
+    ("bloom", "ref"), ("dedup_deposit", "ref"), ("opic_update", "ref")])
+def test_registry_tpu_defaults(kern, want, monkeypatch):
+    """On a TPU, auto runs the kernels Mosaic compiles (the select family)
+    and the ref path for the families it refuses (tests/
+    test_chip_compile.py compiles the former for a described v5e)."""
+    monkeypatch.setattr(registry.jax, "default_backend", lambda: "tpu")
+    assert registry.resolve_impl(kern, "auto") == want
+
+
 def test_registry_rejects_unknown():
     with pytest.raises(KeyError):
         registry.available("no_such_kernel")
