@@ -90,10 +90,11 @@ class CrawlSession:
         self.state: CrawlState = self._init()
         self._t = 0
         self._chunk_fn = None          # built lazily on first scan use
-        # -- observability (DESIGN.md §17); off -> all hooks are dead code on
-        # the step path and the compiled programs are the untraced ones
+        # -- observability (DESIGN.md §17); off -> the compiled programs are
+        # the untraced ones and spans only annotate the profiler's clock
         self.telemetry = obs.telemetry_enabled(cfg)
-        self.tracer = tracer if tracer is not None else obs.Tracer()
+        self.tracer = (tracer if tracer is not None
+                       else obs.Tracer(record=self.telemetry))
         self.ledger = (obs.LedgerBuffer(obs.ledger_metrics(cfg), self.n_shards)
                        if self.telemetry else None)
         self._snap_fn = None           # eager-path ledger snapshot, lazy
@@ -141,21 +142,19 @@ class CrawlSession:
         from the step counter. Returns that step's FetchReport."""
         dispatch = (self._t + 1) % self.cfg.dispatch_interval == 0
         fn = self._step_d if dispatch else self._step_f
-        if not self.telemetry:
+        with self.tracer.span("CrawlSession.step", "stage", t=self._t,
+                              dispatch=int(dispatch)):
             self.state, rep = fn(self.state)
-            self._t += 1
-            return rep
-        name = "step_dispatch" if dispatch else "step_fetch"
-        with self.tracer.span(name, "stage", t=self._t):
-            self.state, rep = fn(self.state)
-            row = np.asarray(self._snapshot()(
-                self.state, jnp.float32(1.0 if dispatch else 0.0)))
-            jax.block_until_ready(self.state)
+            if self.telemetry:
+                row = np.asarray(self._snapshot()(
+                    self.state, jnp.float32(1.0 if dispatch else 0.0)))
+                jax.block_until_ready(self.state)
         self._t += 1
-        self.ledger.append(self._t, row)
-        if dispatch:
-            self._emit_counters()
-            self.maybe_rebalance()
+        if self.telemetry:
+            self.ledger.append(self._t, row)
+            if dispatch:
+                self._emit_counters()
+                self.maybe_rebalance()
         return rep
 
     def run_chunk(self) -> FetchReport:
@@ -173,18 +172,18 @@ class CrawlSession:
             self._chunk_fn = chunk_program(
                 self.cfg, self.mesh, axes=self.axes,
                 telemetry=self.telemetry, **self._kw)
-        if not self.telemetry:
-            self.state, reps = self._chunk_fn(self.state)
-            self._t += iv
-            return reps
-        with self.tracer.span("run_chunk", "stage", t=self._t, interval=iv):
-            self.state, reps, rows = self._chunk_fn(self.state)
-            rows = np.asarray(rows)           # blocks on the chunk's result
-            jax.block_until_ready(self.state)
+        with self.tracer.span("CrawlSession.run_chunk", "stage", t=self._t,
+                              interval=iv):
+            out = self._chunk_fn(self.state)
+            self.state, reps = out[:2]
+            if self.telemetry:
+                rows = np.asarray(out[2])     # blocks on the chunk's result
+                jax.block_until_ready(self.state)
         t0, self._t = self._t, self._t + iv
-        self.ledger.append_block(range(t0 + 1, t0 + iv + 1), rows)
-        self._emit_counters()
-        self.maybe_rebalance()
+        if self.telemetry:
+            self.ledger.append_block(range(t0 + 1, t0 + iv + 1), rows)
+            self._emit_counters()
+            self.maybe_rebalance()
         return reps
 
     # -- telemetry plumbing --------------------------------------------------
@@ -288,9 +287,8 @@ class CrawlSession:
         """Mark crawl process(es) dead (wraps ``crawler.mark_dead``)."""
         shards = [shards] if isinstance(shards, int) else list(shards)
         self.state = CR.mark_dead(self.state, shards)
-        if self.telemetry:
-            self.tracer.instant("inject_failure", "fault", t=self._t,
-                                shards=list(shards))
+        self.tracer.instant("inject_failure", "fault", t=self._t,
+                            shards=list(shards))
         return self
 
     def heal(self, shards: Union[int, Sequence[int], None] = None
@@ -310,9 +308,7 @@ class CrawlSession:
         if not shards:
             raise ValueError("heal: no dead shards in state and none given")
         self.state = heal_crawler(self.state, self.cfg, shards, self.n_shards)
-        if self.telemetry:
-            self.tracer.instant("heal", "fault", t=self._t,
-                                shards=list(shards))
+        self.tracer.instant("heal", "fault", t=self._t, shards=list(shards))
         return self
 
     # -- load-driven elastic repartitioning (DESIGN.md §18) ------------------
@@ -355,8 +351,8 @@ class CrawlSession:
         decision = self._rebalance.plan(self.cfg, dm, row_depth, row_cash)
         if decision is None:
             return None
-        with self.tracer.span("rebalance", "rebalance", t=self._t,
-                              n_moves=len(decision.moves)):
+        with self.tracer.span("CrawlSession.rebalance", "rebalance",
+                              t=self._t, n_moves=len(decision.moves)):
             self.state = CR.apply_rebalance(state, self.cfg,
                                             decision.new_map)
             jax.block_until_ready(self.state)
@@ -375,39 +371,37 @@ class CrawlSession:
         telemetry on, the ledger time-series checkpoints alongside (an
         ``obs/`` subdir) so a restore continues it instead of forgetting."""
         from repro.train import checkpoint as ckpt
-        if not self.telemetry:
-            return ckpt.save(ckpt_dir, self._t, self.state, keep=keep)
-        with self.tracer.span("checkpoint", "io", step=self._t):
+        with self.tracer.span("CrawlSession.checkpoint", "io", step=self._t):
             path = ckpt.save(ckpt_dir, self._t, self.state, keep=keep)
-            steps, rows = self.ledger.arrays()
-            ckpt.save(os.path.join(ckpt_dir, _OBS_DIR), self._t,
-                      {"steps": steps, "rows": rows}, keep=keep)
+            if self.telemetry:
+                steps, rows = self.ledger.arrays()
+                ckpt.save(os.path.join(ckpt_dir, _OBS_DIR), self._t,
+                          {"steps": steps, "rows": rows}, keep=keep)
         return path
 
     def restore(self, ckpt_dir: str, *, step: Optional[int] = None
                 ) -> "CrawlSession":
         """Restore state (latest step by default) and resync the counter."""
         from repro.train import checkpoint as ckpt
-        if not self.telemetry:
+        with self.tracer.span("CrawlSession.restore", "io"):
             self.state = ckpt.restore(ckpt_dir, self.state, step=step)
             self._t = int(np.asarray(self.state.step))
-            return self
-        with self.tracer.span("restore", "io"):
-            self.state = ckpt.restore(ckpt_dir, self.state, step=step)
-            self._t = int(np.asarray(self.state.step))
-            # ledger shapes come from the file — any-length target works
-            target = {"steps": np.zeros((0,), np.int64),
-                      "rows": np.zeros(
-                          (0, self.n_shards, len(self.ledger.names)),
-                          np.float32)}
-            try:
-                led = ckpt.restore(os.path.join(ckpt_dir, _OBS_DIR), target,
-                                   step=self._t)
-                self.ledger.load(np.asarray(led["steps"]),
-                                 np.asarray(led["rows"]))
-            except FileNotFoundError:
-                self.ledger.clear()    # pre-telemetry checkpoint: start fresh
+            if self.telemetry:
+                self._restore_ledger(ckpt_dir)
         return self
+
+    def _restore_ledger(self, ckpt_dir: str) -> None:
+        from repro.train import checkpoint as ckpt
+        # ledger shapes come from the file — any-length target works
+        target = {"steps": np.zeros((0,), np.int64),
+                  "rows": np.zeros((0, self.n_shards, len(self.ledger.names)),
+                                   np.float32)}
+        try:
+            led = ckpt.restore(os.path.join(ckpt_dir, _OBS_DIR), target,
+                               step=self._t)
+            self.ledger.load(np.asarray(led["steps"]), np.asarray(led["rows"]))
+        except FileNotFoundError:
+            self.ledger.clear()        # pre-telemetry checkpoint: start fresh
 
 
 def chunk_program(cfg: CrawlConfig, mesh, *, axes=("data",),

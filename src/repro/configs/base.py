@@ -253,9 +253,10 @@ class CrawlConfig:
                                       # collect the per-shard, per-step load
                                       # ledger inside the step/scan (extra
                                       # stacked device output — no host
-                                      # callbacks in the hot path) and attach
-                                      # a wall-clock span tracer to the
-                                      # session. Off = bit-for-bit the
+                                      # callbacks in the hot path) and record
+                                      # the session's spans for export
+                                      # (profiler annotations are always
+                                      # on). Off = bit-for-bit the
                                       # untraced program (test-enforced).
                                       # REPRO_TELEMETRY=1 flips it on
                                       # globally (CI invariants cell).

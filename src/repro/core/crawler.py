@@ -79,7 +79,12 @@ def make_crawl_step(cfg: CrawlConfig, *, n_shards: int, axes,
     WHOLE per-step pipeline verbatim (expert mode — the first stage must
     create the StepCarry, as ``stages.allocate`` does, and a stateful
     ordering's update stage must be included by hand). ``dispatch_stage``
-    runs only on exchange steps."""
+    runs only on exchange steps.
+
+    Each stage's operations carry a named scope (``stage/allocate``,
+    ``stage/fetch_analyze``, ``stage/extract``, ``stage/dispatch``; other
+    stages ``stage/<function name>``): trace-time metadata that costs
+    nothing at run time and lets a device profile split the step."""
     ctx = ST.make_context(cfg, n_shards=n_shards, axes=axes,
                           score_fn=score_fn,
                           classify_accuracy=classify_accuracy)
@@ -94,11 +99,13 @@ def make_crawl_step(cfg: CrawlConfig, *, n_shards: int, axes,
                    ) -> Tuple[CrawlState, FetchReport]:
         carry = None
         for stage in pipeline:
-            state, carry, delta = stage(ctx, state, carry)
-            state = ST.apply_delta(state, delta)
+            with jax.named_scope(ST.scope_name(stage)):
+                state, carry, delta = stage(ctx, state, carry)
+                state = ST.apply_delta(state, delta)
         if dispatch:
-            state, carry, delta = dispatch_stage(ctx, state, carry)
-            state = ST.apply_delta(state, delta)
+            with jax.named_scope("stage/dispatch"):
+                state, carry, delta = dispatch_stage(ctx, state, carry)
+                state = ST.apply_delta(state, delta)
         state = state._replace(step=state.step + 1)
         return state, FetchReport(jnp.where(carry.sel, carry.urls, 0),
                                   carry.sel)

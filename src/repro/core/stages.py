@@ -639,9 +639,10 @@ def dispatch_exchange(ctx: StageContext, state: CrawlState, carry: StepCarry
         fr, table, ins_refund = F.place_valued(
             fr, table, rb, fresh, jnp.where(fresh, rv, 0.0), impl=ctx.impl)
         order_state = _with_lane(order_state, table, dup_refund + ins_refund)
-        fr = F.rescore(fr, ctx.score_fn(fr.url, cfg, state,
-                                        val=order_state[:, ORD_URL0:]),
-                       n_buckets=cfg.n_priority_buckets)
+        with jax.named_scope("rescore"):
+            fr = F.rescore(fr, ctx.score_fn(fr.url, cfg, state,
+                                            val=order_state[:, ORD_URL0:]),
+                           n_buckets=cfg.n_priority_buckets)
     else:
         bloom = DD.Bloom(state.bloom_bits, cfg.bloom_bits_log2)
         seen, bloom = DD.probe_insert(bloom, rb, rbmask, k=cfg.bloom_hashes,
@@ -681,9 +682,10 @@ def dispatch_exchange(ctx: StageContext, state: CrawlState, carry: StepCarry
             # in-link cash accumulated since insert re-ranks queued URLs
             # once per exchange (the bounded-cost point to refresh every
             # queue at once)
-            fr = F.rescore(fr, ctx.score_fn(fr.url, cfg, state,
-                                            val=order_state[:, ORD_URL0:]),
-                           n_buckets=cfg.n_priority_buckets)
+            with jax.named_scope("rescore"):
+                fr = F.rescore(fr, ctx.score_fn(fr.url, cfg, state,
+                                                val=order_state[:, ORD_URL0:]),
+                               n_buckets=cfg.n_priority_buckets)
         else:
             scores = _entry_scores(ctx, state, rb, rbf)
             fr = F.insert(fr, rb, scores, fresh,
@@ -700,6 +702,16 @@ def dispatch_exchange(ctx: StageContext, state: CrawlState, carry: StepCarry
 
 
 DEFAULT_PIPELINE: Tuple[Stage, ...] = (allocate, fetch_analyze, extract_stage)
+
+# the named scope each pipeline stage's operations carry in the compiled
+# step (``stage/<name>``; core/crawler.py opens it), so a device profile
+# gives each stage's self time; a stage not listed goes by its function name
+_SCOPES = {allocate: "allocate", fetch_analyze: "fetch_analyze",
+           extract_stage: "extract"}
+
+
+def scope_name(stage: Stage) -> str:
+    return "stage/" + _SCOPES.get(stage, getattr(stage, "__name__", "stage"))
 
 
 def assemble_pipeline(ctx: StageContext,

@@ -93,11 +93,12 @@ def render_spans(events) -> str:
     totals = span_totals(events)
     if not totals:
         return "(no spans)"
-    lines = [f"{'category':>10}  {'span':<16} {'count':>6}  {'total':>9}  "
+    w = max(16, *(len(name) for _, name in totals))
+    lines = [f"{'category':>10}  {'span':<{w}} {'count':>6}  {'total':>9}  "
              f"{'mean':>9}"]
     for (cat, name), (n, tot) in sorted(totals.items(),
                                         key=lambda kv: -kv[1][1]):
-        lines.append(f"{cat:>10}  {name:<16} {n:>6}  {tot:>8.3f}s  "
+        lines.append(f"{cat:>10}  {name:<{w}} {n:>6}  {tot:>8.3f}s  "
                      f"{tot / n * 1e3:>7.2f}ms")
     return "\n".join(lines)
 

@@ -6,10 +6,11 @@ Three pieces, threaded through ``CrawlSession``/``ServeSession``:
     metric rows snapshotted INSIDE the fused ``run_chunk`` scan (an extra
     stacked output — the hot path traces no host callbacks), accumulated
     host-side as a ``(n_records, n_shards, n_metrics)`` time-series;
-  * ``trace``   — wall-clock span tracing around every stage boundary the
-    host can see (chunk launches, eager steps, dispatch, checkpoint/
-    restore, serve query batches), exportable as Chrome ``trace_event``
-    JSON and JSONL, with optional ``jax.profiler`` annotation passthrough;
+  * ``trace``   — span tracing around every boundary the host can see
+    (chunk launches, eager steps, checkpoint/restore, the serve loop's
+    phases), always on the ``jax.profiler`` clock as annotations, and
+    with telemetry on recorded for export as Chrome ``trace_event`` JSON
+    and JSONL;
   * ``health``  — derived skew/health metrics over the ledger (load
     imbalance factor, comm-per-page trend, frontier growth, freshness
     lag), surfaced as ``CrawlReport.telemetry`` / ``ServeReport.telemetry``.

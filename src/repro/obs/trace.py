@@ -1,17 +1,38 @@
-"""Wall-clock span tracing + Chrome ``trace_event`` export (DESIGN.md §17).
+"""Span tracing on the profiler's clock + Chrome ``trace_event`` export
+(DESIGN.md §17).
 
-The tracer records what the HOST can honestly see: spans around each fused
-chunk launch, eager step, dispatch, checkpoint/restore, index fold, and
-serve query batch (the session blocks on the device result inside the span,
-so durations are real compute, not async-dispatch returns), instant markers
-for C4 fail/heal events, and counter series sampled from the load ledger at
-interval boundaries. Inside-jit structure is NOT faked with host clocks —
-per-kernel visibility comes from the ``jax.profiler`` passthrough instead:
-``kernels/registry.py`` wraps every resolved kernel launch in a named scope
-when annotation is enabled, so device profiles label each kernel-family
-region, and ``Tracer(profiler=True)`` (or ``REPRO_PROFILER_ANNOTATIONS=1``)
-additionally mirrors host spans into ``jax.profiler.TraceAnnotation``
-ranges for ``jax.profiler.trace`` captures.
+Every span enters a ``jax.profiler.TraceAnnotation`` — always, whatever
+the session's telemetry says — so a ``jax.profiler`` capture shows the
+host phases on the same clock as the device's operations. Outside a
+capture a span costs a few microseconds and reads nothing from
+the device. A span's arguments are host-known integers (queries in a
+batch, batch index, interval number); no span ever waits for the device.
+When the tracer *records* (the session's telemetry is on) each span is
+also appended as an :class:`Event` for the Chrome/JSONL export, together
+with instant markers for C4 fail/heal events and counter series sampled
+from the load ledger at interval boundaries; the telemetry path then
+blocks on the device result inside its spans, so recorded durations are
+compute, not async-dispatch returns.
+
+Span names carry the session that opens them, and they nest:
+
+  * ``CrawlSession.run_chunk`` — one fused interval's launch;
+    ``CrawlSession.step`` — one eager step; ``CrawlSession.checkpoint`` /
+    ``CrawlSession.restore`` / ``CrawlSession.rebalance``;
+  * ``ServeSession.run`` — one call, the parent of ``ServeSession.chunk``
+    (the crawl chunk and its wait), ``ServeSession.take`` (the interval's
+    arrivals), ``ServeSession.query_batch`` (one batch: launch, wait,
+    top-k copy), ``ServeSession.fold`` (the index add),
+    ``ServeSession.harvest`` (fetched URLs to the host) and
+    ``ServeSession.report`` (the end-of-call counters).
+
+Inside-jit structure is not faked with host clocks: the fused chunk names
+its stages with ``jax.named_scope`` (``stage/allocate``,
+``stage/fetch_analyze``, ``stage/extract``, ``stage/dispatch`` with
+``stage/dispatch/rescore``, scenario stages by function name;
+core/crawler.py), and ``kernels/registry.py`` wraps every resolved kernel
+launch in ``kernel/<family>.<impl>`` when annotation is enabled, so device
+profiles label each stage and kernel-family region.
 
 Export formats:
   * ``.json``  — a Chrome ``trace_event`` document (``chrome://tracing`` /
@@ -29,10 +50,11 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import os
 import time
 from contextlib import contextmanager
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Tuple
+
+import jax
 
 
 @dataclasses.dataclass
@@ -48,49 +70,49 @@ class Event:
 
 
 class Tracer:
-    """Accumulates :class:`Event` records; cheap enough to leave on (one
-    list append per host-visible boundary — never inside jitted code)."""
+    """Opens profiler annotations around host phases and, when ``record``
+    is on, accumulates :class:`Event` records (one list append per
+    host-visible boundary — never inside jitted code)."""
 
-    def __init__(self, *, profiler: Optional[bool] = None):
+    def __init__(self, *, record: bool = True):
         self.events: List[Event] = []
+        self.record = bool(record)
         self._origin = time.perf_counter()
-        if profiler is None:
-            profiler = os.environ.get(
-                "REPRO_PROFILER_ANNOTATIONS", "0") not in ("", "0")
-        self.profiler = bool(profiler)
 
     def now(self) -> float:
         return time.perf_counter() - self._origin
 
     @contextmanager
     def span(self, name: str, cat: str = "stage", **args):
-        """Record a complete ("X") event around the body. Callers that time
-        device work must block on the result inside the span — the span is
-        a wall-clock claim, and an async dispatch return is not compute."""
-        if self.profiler:
-            import jax
-            ann = jax.profiler.TraceAnnotation(name)
-            ann.__enter__()
-        t0 = self.now()
-        try:
-            yield self
-        finally:
-            if self.profiler:
-                ann.__exit__(None, None, None)
+        """A profiler annotation around the body, and with ``record`` on a
+        complete ("X") event. Yields the span's argument dict: keys the
+        body adds (e.g. a count known only once the body ran) reach both
+        the annotation and the event."""
+        given = dict(args)
+        t0 = self.now() if self.record else 0.0
+        with jax.profiler.TraceAnnotation(name, **given) as ann:
+            yield args
+            extra = {k: v for k, v in args.items() if k not in given}
+            if extra:
+                ann.set_metadata(**extra)
+        if self.record:
             self.events.append(Event(name=name, cat=cat, ph="X", ts=t0,
                                      dur=self.now() - t0, args=dict(args)))
 
     def instant(self, name: str, cat: str = "event", **args) -> None:
-        self.events.append(Event(name=name, cat=cat, ph="i", ts=self.now(),
-                                 args=dict(args)))
+        if self.record:
+            self.events.append(Event(name=name, cat=cat, ph="i",
+                                     ts=self.now(), args=dict(args)))
 
     def counter(self, name: str, values: Dict[str, float],
                 cat: str = "ledger") -> None:
         """One counter sample: ``values`` maps series name (e.g. ``shard0``)
         to the sampled value — Chrome renders them as stacked area rows."""
-        self.events.append(Event(name=name, cat=cat, ph="C", ts=self.now(),
-                                 args={k: float(v) for k, v in
-                                       values.items()}))
+        if self.record:
+            self.events.append(Event(name=name, cat=cat, ph="C",
+                                     ts=self.now(),
+                                     args={k: float(v) for k, v in
+                                           values.items()}))
 
     # -- export -------------------------------------------------------------
 

@@ -142,69 +142,80 @@ class ServeSession:
         per_step: List[int] = []
         crawl_secs = serve_secs = 0.0
         led0 = len(self.crawl.ledger) if self.telemetry else 0
+        span = self.tracer.span
         run_w0 = time.perf_counter()
 
-        for _ in range(steps // iv):
-            t_start = self.crawl.t
-            w0 = time.perf_counter()
-            reps = self.crawl.run_chunk()
-            jax.block_until_ready(reps)
-            w1 = time.perf_counter()
-            crawl_secs += w1 - w0
-            t_now = self.crawl.t
+        with span("ServeSession.run", "serve", steps=steps):
+            for i in range(steps // iv):
+                t_start = self.crawl.t
+                w0 = time.perf_counter()
+                with span("ServeSession.chunk", "serve", interval=i):
+                    reps = self.crawl.run_chunk()
+                    jax.block_until_ready(reps)
+                w1 = time.perf_counter()
+                crawl_secs += w1 - w0
+                t_now = self.crawl.t
 
-            # 2. answer the interval's arrivals from the live (lagging) index
-            qb = self.load.take(self._q_cursor, float(t_now))
-            self._q_cursor = qb.cursor
-            if len(qb):
-                serve_secs += self._serve(qb, t_start, t_now, w0, w1,
-                                          lat, arr, lags, top_u, top_s)
-                q_dom.append(qb.domain)
-                q_seed.append(qb.seed)
+                # 2. answer the interval's arrivals from the live (lagging)
+                # index
+                with span("ServeSession.take", "serve", interval=i) as args:
+                    qb = self.load.take(self._q_cursor, float(t_now))
+                    args["queries"] = len(qb)
+                self._q_cursor = qb.cursor
+                if len(qb):
+                    serve_secs += self._serve(qb, t_start, t_now, w0, w1,
+                                              lat, arr, lags, top_u, top_s)
+                    q_dom.append(qb.domain)
+                    q_seed.append(qb.seed)
 
-            # 3. stream the chunk's pages into the index (incremental fold)
-            self._pending.append(reps)
-            if len(self._pending) >= self.index_every:
-                self._flush_pending()
-            u, c = harvest(reps)
-            per_step.extend(c)
-            self._all_urls.extend(u)
-            if collect == "urls":
-                url_parts.extend(u)
+                # 3. stream the chunk's pages into the index (incremental
+                # fold)
+                self._pending.append(reps)
+                if len(self._pending) >= self.index_every:
+                    self._flush_pending()
+                with span("ServeSession.harvest", "serve", interval=i):
+                    u, c = harvest(reps)
+                    per_step.extend(c)
+                    self._all_urls.extend(u)
+                    if collect == "urls":
+                        url_parts.extend(u)
 
-        seconds = time.perf_counter() - run_w0
-        crawl_tel = self.crawl.telemetry_report(start=led0)
-        crawl_rep = CrawlReport(
-            urls=(np.concatenate(url_parts) if url_parts
-                  else np.array([], np.uint32)),
-            per_step=np.asarray(per_step, np.int64),
-            stats=stats_dict(self.crawl.state), seconds=crawl_secs,
-            cfg=self.cfg,
-            stats_per_shard=stats_per_shard(self.crawl.state),
-            telemetry=crawl_tel)
-        top_u_a = (np.concatenate(top_u) if top_u
-                   else np.zeros((0, self.top_k), np.uint32))
-        top_s_a = (np.concatenate(top_s) if top_s
-                   else np.zeros((0, self.top_k), np.float32))
-        rec = None
-        if recall and len(top_u_a) and self._all_urls:
-            rec = self._oracle_recall(
-                np.concatenate(q_seed), np.concatenate(q_dom), top_u_a)
-        lat_a = np.asarray(lat, np.float64)
-        lags_a = np.asarray(lags, np.int64)
-        serve_tel = None
-        if crawl_tel is not None:
-            from repro.obs.health import ServeTelemetry
-            serve_tel = ServeTelemetry(crawl=crawl_tel, lag_steps=lags_a,
-                                       latency_ms=lat_a)
-        return ServeReport(
-            crawl=crawl_rep, latency_ms=lat_a,
-            arrival_step=np.asarray(arr, np.float64),
-            lag_steps=lags_a,
-            top_urls=top_u_a, top_scores=top_s_a, k=self.top_k,
-            seconds=seconds, serve_seconds=serve_secs,
-            index=self.index_stats(), recall_at_k=rec, cfg=self.cfg,
-            telemetry=serve_tel)
+            seconds = time.perf_counter() - run_w0
+            with span("ServeSession.report", "serve"):
+                crawl_tel = self.crawl.telemetry_report(start=led0)
+                crawl_rep = CrawlReport(
+                    urls=(np.concatenate(url_parts) if url_parts
+                          else np.array([], np.uint32)),
+                    per_step=np.asarray(per_step, np.int64),
+                    stats=stats_dict(self.crawl.state), seconds=crawl_secs,
+                    cfg=self.cfg,
+                    stats_per_shard=stats_per_shard(self.crawl.state),
+                    telemetry=crawl_tel)
+                top_u_a = (np.concatenate(top_u) if top_u
+                           else np.zeros((0, self.top_k), np.uint32))
+                top_s_a = (np.concatenate(top_s) if top_s
+                           else np.zeros((0, self.top_k), np.float32))
+                rec = None
+                if recall and len(top_u_a) and self._all_urls:
+                    rec = self._oracle_recall(
+                        np.concatenate(q_seed), np.concatenate(q_dom),
+                        top_u_a)
+                lat_a = np.asarray(lat, np.float64)
+                lags_a = np.asarray(lags, np.int64)
+                serve_tel = None
+                if crawl_tel is not None:
+                    from repro.obs.health import ServeTelemetry
+                    serve_tel = ServeTelemetry(crawl=crawl_tel,
+                                               lag_steps=lags_a,
+                                               latency_ms=lat_a)
+                return ServeReport(
+                    crawl=crawl_rep, latency_ms=lat_a,
+                    arrival_step=np.asarray(arr, np.float64),
+                    lag_steps=lags_a,
+                    top_urls=top_u_a, top_scores=top_s_a, k=self.top_k,
+                    seconds=seconds, serve_seconds=serve_secs,
+                    index=self.index_stats(), recall_at_k=rec, cfg=self.cfg,
+                    telemetry=serve_tel)
 
     def _serve(self, qb: QueryBatch, t_start: int, t_now: int,
                w0: float, w1: float, lat, arr, lags, top_u, top_s) -> float:
@@ -217,41 +228,34 @@ class ServeSession:
         arrival_wall = w0 + np.clip(frac, 0.0, 1.0) * (w1 - w0)
         spent = 0.0
         for lo in range(0, len(qb), B):
-            seeds = np.zeros((B,), np.uint32)
-            doms = np.zeros((B,), np.int32)
             n = min(B, len(qb) - lo)
-            seeds[:n] = qb.seed[lo:lo + n]
-            doms[:n] = qb.domain[lo:lo + n]
-            b0 = time.perf_counter()
-            if self.telemetry:
-                with self.tracer.span("query_batch", "serve", n=n,
-                                      lag_steps=lag):
-                    s, u = self._query_fn(self.index, jnp.asarray(seeds),
-                                          jnp.asarray(doms))
-                    jax.block_until_ready((s, u))
-            else:
+            with self.tracer.span("ServeSession.query_batch", "serve", n=n,
+                                  batch=lo // B, lag_steps=lag):
+                seeds = np.zeros((B,), np.uint32)
+                doms = np.zeros((B,), np.int32)
+                seeds[:n] = qb.seed[lo:lo + n]
+                doms[:n] = qb.domain[lo:lo + n]
+                b0 = time.perf_counter()
                 s, u = self._query_fn(self.index, jnp.asarray(seeds),
                                       jnp.asarray(doms))
                 jax.block_until_ready((s, u))
-            done = time.perf_counter()
+                done = time.perf_counter()
+                top_u.append(np.asarray(u[:n], np.uint32))
+                top_s.append(np.asarray(s[:n], np.float32))
             spent += done - b0
             lat.extend((done - arrival_wall[lo:lo + n]) * 1e3)
             arr.extend(qb.time[lo:lo + n])
             lags.extend([lag] * n)
-            top_u.append(np.asarray(u[:n], np.uint32))
-            top_s.append(np.asarray(s[:n], np.float32))
         return spent
 
     def _flush_pending(self) -> None:
-        if self.telemetry and self._pending:
-            with self.tracer.span("index_fold", "serve",
+        if self._pending:
+            with self.tracer.span("ServeSession.fold", "serve",
                                   n_intervals=len(self._pending)):
                 for rep in self._pending:
                     self.index = self._add_fn(self.index, rep)
-                jax.block_until_ready(self.index)
-        else:
-            for rep in self._pending:
-                self.index = self._add_fn(self.index, rep)
+                if self.telemetry:
+                    jax.block_until_ready(self.index)
         self._pending = []
         self._watermark = self.crawl.t
 
