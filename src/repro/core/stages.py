@@ -76,9 +76,9 @@ from repro.core import webgraph as W
 
 # stats counters (per shard)
 STATS = ("fetched", "fetch_own", "fetch_foreign", "discovered", "dedup_exact",
-         "dedup_bloom", "staging_drop", "frontier_drop", "dispatch_sent",
-         "dispatch_recv", "dispatch_rounds", "revived",
-         "politeness_deferred", "revisit_enqueued",
+         "dedup_bloom", "dedup_tiles", "dedup_dense_tiles", "staging_drop",
+         "frontier_drop", "dispatch_sent", "dispatch_recv", "dispatch_rounds",
+         "revived", "politeness_deferred", "revisit_enqueued",
          "coord_dropped", "coord_deferred")
 NSTAT = len(STATS)
 SIDX = {n: i for i, n in enumerate(STATS)}
@@ -625,12 +625,16 @@ def dispatch_exchange(ctx: StageContext, state: CrawlState, carry: StepCarry
         # tensor of the unfused path never materializes), accumulates each
         # twin's cash into its cell, and sums the no-twin refunds
         from repro.kernels.dedup_deposit.ops import dedup_deposit
+        from repro.kernels.dedup_deposit.ref import tile_walk
         seen, bbits, table, dup_refund = dedup_deposit(
             state.bloom_bits, rb, rbmask, rv, fr.url, fr.valid,
             order_state[:, ORD_URL0:], k=cfg.bloom_hashes, impl=ctx.impl)
         bloom = DD.Bloom(bbits, cfg.bloom_bits_log2)
         fresh = rbmask & ~seen
         delta["dedup_bloom"] = (rbmask & seen).sum()
+        # the URL tiles the ref walk visits, and those too full for one
+        # compacted pass
+        delta["dedup_tiles"], delta["dedup_dense_tiles"] = tile_walk(rbmask)
         # placeholder-priority insert: the whole-queue rescore below is the
         # ONLY scoring pass (the rescore fold — unfused insert-time
         # priorities are never observed before that rescore overwrites

@@ -11,6 +11,9 @@ The topology is described inside a module-scoped fixture, never at import
 time: only one process at a time may load the TPU library, and every test
 worker imports this file.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -51,6 +54,18 @@ def _compile(fn, sharding, *shapes):
     return jax.jit(fn).lower(*args).compile()
 
 
+def _scatter_updates(hlo: str, into: str) -> list:
+    """Update counts of the scatters in ``hlo`` whose result is ``into``
+    (an HLO shape such as ``u8[512,8388608]``)."""
+    shape = dict(re.findall(r"%([\w.\-]+) = \w+\[([\d,]*)\]", hlo))
+    counts = []
+    pat = re.escape(f"= {into}") + r"\{[^}]*\} scatter\(([^)]*)\)"
+    for m in re.finditer(pat, hlo):
+        upd = m.group(1).split(", ")[-1].lstrip("%")
+        counts.append(math.prod(int(d) for d in shape[upd].split(",") if d))
+    return counts
+
+
 def _fits(compiled, sharding) -> bool:
     from repro.launch.mesh import chip_peaks
     ma = compiled.memory_analysis()
@@ -85,7 +100,10 @@ def test_select_harvest_compiles_for_v5e(v5e, k):
 
 def test_ref_dedup_deposit_fits_v5e_at_deployment_bloom(v5e):
     """The dispatch's Bloom + twin-deposit pass (the ref implementation the
-    chip resolves to) at deployment A's filter size."""
+    chip resolves to) at deployment A's filter size. Its Bloom insert
+    scatters the compacted arrivals' bits, never the padded (R, url_tile)
+    tile's R * 256 * k = 524288 (each masked slot a no-op update that still
+    costs its time on the chip)."""
     from repro.kernels.dedup_deposit.ops import dedup_deposit
     c = _compile(lambda *a: dedup_deposit(*a, k=4, impl="ref"), v5e,
                  ((R, 1 << BLOOM_BITS_LOG2), jnp.uint8),
@@ -93,3 +111,7 @@ def test_ref_dedup_deposit_fits_v5e_at_deployment_bloom(v5e):
                  ((R, M), jnp.float32), ((R, C), jnp.uint32),
                  ((R, C), jnp.bool_), ((R, C), jnp.float32))
     assert _fits(c, v5e)
+    inserts = _scatter_updates(c.as_text(),
+                               f"u8[{R},{1 << BLOOM_BITS_LOG2}]")
+    assert inserts, "no scatter into the Bloom bits found"
+    assert R * 256 * 4 not in inserts, inserts

@@ -257,7 +257,19 @@ def test_dedup_deposit_matches_unfused_composition():
         keep[first] = True
         m[r] &= keep
     urls, mask = jnp.asarray(u), jnp.asarray(m)
-    seen_u, bits_u = probe_insert(bits, urls, mask, k=3, impl="ref")
+    got = dedup_deposit(bits, urls, mask, val, f_url, f_valid, table, k=3,
+                        impl="ref")
+    _assert_matches_unfused(got, bits, urls, mask, val, f_url, f_valid,
+                            table)
+
+
+def _assert_matches_unfused(got, bits, urls, mask, val, f_url, f_valid,
+                            table, url_tile=256):
+    """``got`` (seen, bits', table', refund) against the unfused dispatch
+    composition: probe_insert -> (R, M, C) twin match -> cell scatter."""
+    u = np.asarray(urls)
+    seen_u, bits_u = probe_insert(bits, urls, mask, k=3, impl="ref",
+                                  url_tile=url_tile)
     seen_u = np.asarray(seen_u) & np.asarray(mask)
     twin = (u[:, :, None] == np.asarray(f_url)[:, None, :]) \
         & np.asarray(f_valid)[:, None, :] & seen_u[:, :, None]
@@ -267,12 +279,106 @@ def test_dedup_deposit_matches_unfused_composition():
     rows, cols = np.nonzero(hit)
     tab[rows, cell[rows, cols]] += np.asarray(val)[rows, cols]
     refund_u = np.where(seen_u & ~hit, np.asarray(val), 0.0).sum(1)
-    seen, bits2, table2, refund = dedup_deposit(
-        bits, urls, mask, val, f_url, f_valid, table, k=3, impl="ref")
+    seen, bits2, table2, refund = got
     np.testing.assert_array_equal(np.asarray(seen), seen_u)
     np.testing.assert_array_equal(np.asarray(bits2), np.asarray(bits_u))
     np.testing.assert_array_equal(np.asarray(table2), tab)
     np.testing.assert_allclose(np.asarray(refund), refund_u, rtol=1e-6)
+
+
+def _crawl_dedup_inputs(R, M, C, b, *, per_row, long_row, seed):
+    """Crawl-shaped arrivals: each row's bucket filled as a prefix (as the
+    dispatch's per-row bucketing fills it) at about ``per_row`` of M, one
+    row holding ``long_row``; URLs distinct within a row (the exact-dedup
+    upstream), a third of them queued twins, a third fetched-and-gone,
+    a third fresh."""
+    rng = np.random.default_rng(seed)
+    f_url = np.stack([rng.choice(np.arange(1, 1 << 20), C, replace=False)
+                      for _ in range(R)]).astype(np.uint32)
+    f_valid = rng.random((R, C)) < 0.7
+    table = rng.random((R, C)).astype(np.float32) * f_valid
+    n = rng.binomial(2 * per_row, 0.5, R)
+    n[R // 2] = long_row
+    mask = np.arange(M)[None, :] < n[:, None]
+    gone = np.stack([rng.choice(np.arange(1 << 20, 1 << 21), M,
+                                replace=False) for _ in range(R)])
+    fresh = np.stack([rng.choice(np.arange(1 << 21, 1 << 22), M,
+                                 replace=False) for _ in range(R)])
+    queued = f_url[:, rng.permutation(np.arange(M) % C)]
+    pick = rng.integers(0, 3, (R, M))
+    urls = np.where(pick == 0, queued, np.where(pick == 1, gone, fresh))
+    for r in range(R):          # queued twins drawn twice: keep the first
+        _, first = np.unique(urls[r], return_index=True)
+        keep = np.zeros(M, bool)
+        keep[first] = True
+        urls[r] = np.where(keep, urls[r], fresh[r])
+    val = rng.random((R, M)).astype(np.float32)
+    bits = jnp.zeros((R, 1 << b), jnp.uint8)
+    _, bits = probe_insert(bits, jnp.asarray(f_url), jnp.asarray(f_valid),
+                           k=3, impl="ref")
+    _, bits = probe_insert(bits, jnp.asarray(gone, jnp.uint32),
+                           jnp.ones((R, M), bool), k=3, impl="ref")
+    return (bits, jnp.asarray(urls, jnp.uint32), jnp.asarray(mask),
+            jnp.asarray(val), jnp.asarray(f_url), jnp.asarray(f_valid),
+            jnp.asarray(table))
+
+
+@pytest.mark.parametrize("impl", ["interpret", "unfused"])
+def test_dedup_deposit_crawl_shaped_sparse(impl):
+    """About 1% of the padded (R, M) grid holds an arrival, each row a
+    prefix, one row past ``url_tile``: the ref walk visits two of four
+    tiles in one compacted pass each and matches the dense walk (the
+    interpret kernel) and the unfused composition bit for bit."""
+    from repro.kernels.dedup_deposit.ops import dedup_deposit
+    from repro.kernels.dedup_deposit.ref import tile_walk
+    args = _crawl_dedup_inputs(32, 1024, 128, 12, per_row=8, long_row=300,
+                               seed=14)
+    mask = args[2]
+    assert 0.005 < float(mask.mean()) < 0.02
+    assert tuple(int(x) for x in tile_walk(mask)) == (2, 0)
+    ref = dedup_deposit(*args, k=3, impl="ref")
+    assert int(np.asarray(ref[0]).sum()) > 0
+    if impl == "unfused":
+        _assert_matches_unfused(ref, *args)
+        return
+    got = dedup_deposit(*args, k=3, impl=impl)
+    for name, a, g in zip(("seen", "bits", "table", "refund"), ref, got):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(g),
+                                      err_msg=f"{impl}: {name} diverged")
+
+
+def test_dedup_deposit_tile_over_k_matches_one_pass():
+    """K = min(R * url_tile, M). The same arrivals in an M = 64 grid (K =
+    64: the first tile's ~200 arrivals take four passes) and in the grid
+    widened to M = 256 by empty tiles (K = 256: one pass) give identical
+    outputs, which are the dense walk's (interpret) and the unfused
+    composition's."""
+    from repro.kernels.dedup_deposit.ops import dedup_deposit
+    from repro.kernels.dedup_deposit.ref import tile_walk
+    R, M, tile = 8, 64, 32
+    bits, urls, mask, val, f_url, f_valid, table = _crawl_dedup_inputs(
+        R, M, 96, 11, per_row=M // 2, long_row=M, seed=3)
+    over = np.asarray(mask).copy()
+    over[:, :tile] = RNG.random((R, tile)) < 0.8       # tile 0: ~205 > 64
+    mask = jnp.asarray(over)
+    wide = [jnp.pad(a, ((0, 0), (0, 256 - M))) for a in (urls, mask, val)]
+    assert tuple(int(x) for x in tile_walk(mask, tile)) == (2, 1)
+    assert tuple(int(x) for x in tile_walk(wide[1], tile)) == (2, 0)
+    narrow = dedup_deposit(bits, urls, mask, val, f_url, f_valid, table, k=3,
+                           impl="ref", url_tile=tile)
+    one = dedup_deposit(bits, *wide, f_url, f_valid, table, k=3, impl="ref",
+                        url_tile=tile)
+    dense = dedup_deposit(bits, urls, mask, val, f_url, f_valid, table, k=3,
+                          impl="interpret", url_tile=tile)
+    assert not np.asarray(one[0])[:, M:].any()
+    for name, a, b, d in zip(("seen", "bits", "table", "refund"), narrow,
+                             (one[0][:, :M],) + tuple(one[1:]), dense):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=f"one pass: {name} diverged")
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(d),
+                                      err_msg=f"dense: {name} diverged")
+    _assert_matches_unfused(narrow, bits, urls, mask, val, f_url, f_valid,
+                            table, url_tile=tile)
 
 
 # ---------------------------------------------------------------------------
