@@ -113,9 +113,10 @@ class CrawlReport:
     @functools.cached_property
     def comm(self) -> Dict[str, float]:
         """The communication-budget ledger (repro/coordination/metrics.py):
-        URLs shipped / received / dropped / deferred by the coordination
-        mode, and the paper's bandwidth metric — shipped URLs per fetched
-        page. Zero-communication modes (firewall, crossover) report
+        URLs shipped / crossed / received / dropped / deferred by the
+        coordination mode, and the paper's bandwidth metric — shipped URLs
+        per fetched page, and crossed (bound for another shard) per page.
+        Zero-communication modes (firewall, crossover) report
         ``comm_per_page == 0``."""
         from repro.coordination.metrics import comm_ledger
         return comm_ledger(self.stats, self.fetched)

@@ -82,8 +82,9 @@ def make_crawl_step(cfg: CrawlConfig, *, n_shards: int, axes,
     runs only on exchange steps.
 
     Each stage's operations carry a named scope (``stage/allocate``,
-    ``stage/fetch_analyze``, ``stage/extract``, ``stage/dispatch``; other
-    stages ``stage/<function name>``): trace-time metadata that costs
+    ``stage/fetch_analyze``, ``stage/extract``, ``stage/dispatch``, with
+    ``stage/dispatch/exchange`` and ``stage/dispatch/rescore`` inside it;
+    other stages ``stage/<function name>``): trace-time metadata that costs
     nothing at run time and lets a device profile split the step."""
     ctx = ST.make_context(cfg, n_shards=n_shards, axes=axes,
                           score_fn=score_fn,

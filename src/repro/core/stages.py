@@ -79,7 +79,7 @@ STATS = ("fetched", "fetch_own", "fetch_foreign", "discovered", "dedup_exact",
          "dedup_bloom", "dedup_tiles", "dedup_dense_tiles", "staging_drop",
          "frontier_drop", "dispatch_sent", "dispatch_recv", "dispatch_rounds",
          "revived", "politeness_deferred", "revisit_enqueued",
-         "coord_dropped", "coord_deferred")
+         "coord_dropped", "coord_deferred", "dispatch_foreign")
 NSTAT = len(STATS)
 SIDX = {n: i for i, n in enumerate(STATS)}
 
@@ -513,7 +513,10 @@ def dispatch_exchange(ctx: StageContext, state: CrawlState, carry: StepCarry
 
     # the coordination decision: ship / keep / defer / drop per item
     plan = coord.plan(ctx, state, shard, u, src, val, dest, staged, valid)
+    # dispatch_sent counts the self-bound URLs the all_to_all hands back to
+    # their sender too; dispatch_foreign only those bound for another shard
     delta = {"dispatch_sent": plan.ship.sum(),
+             "dispatch_foreign": (plan.ship & (dest != shard)).sum(),
              "dispatch_rounds": jnp.ones((), jnp.int32),
              "coord_dropped": plan.drop.sum()}
 
@@ -527,20 +530,23 @@ def dispatch_exchange(ctx: StageContext, state: CrawlState, carry: StepCarry
                                   + (plan.defer & ~parked_ok).sum())
 
     if coord.communicates:
-        payload = jnp.stack([u, pred.astype(jnp.uint32),
-                             plan.ship.astype(jnp.uint32),
-                             lax.bitcast_convert_type(val, jnp.uint32)],
-                            axis=-1)                      # (N, 4)
-        buckets, bmask, dropped, sent = RT.pack_buckets(
-            payload, dest, n_shards, ctx.cap_ex, valid=plan.ship,
-            return_keep=True)
+        # the packing, the all_to_all and the unpacking, and nothing else,
+        # carry the scope stage/dispatch/exchange
+        with jax.named_scope("exchange"):
+            payload = jnp.stack([u, pred.astype(jnp.uint32),
+                                 plan.ship.astype(jnp.uint32),
+                                 lax.bitcast_convert_type(val, jnp.uint32)],
+                                axis=-1)                  # (N, 4)
+            buckets, bmask, dropped, sent = RT.pack_buckets(
+                payload, dest, n_shards, ctx.cap_ex, valid=plan.ship,
+                return_keep=True)
+            recv = RT.exchange(buckets, ctx.axes)      # (n_shards, cap_ex, 4)
+            r_u = recv[..., 0].reshape(-1)
+            r_pred = recv[..., 1].reshape(-1).astype(jnp.int32)
+            r_has = recv[..., 2].reshape(-1) > 0
+            r_val = lax.bitcast_convert_type(recv[..., 3], jnp.float32
+                                             ).reshape(-1)
         delta["staging_drop"] = dropped
-        recv = RT.exchange(buckets, ctx.axes)          # (n_shards, cap_ex, 4)
-        r_u = recv[..., 0].reshape(-1)
-        r_pred = recv[..., 1].reshape(-1).astype(jnp.int32)
-        r_has = recv[..., 2].reshape(-1) > 0
-        r_val = lax.bitcast_convert_type(recv[..., 3], jnp.float32
-                                         ).reshape(-1)
         r_foreign = jnp.zeros_like(r_has)
     else:
         # zero-communication modes: the "received" set is the kept slice of

@@ -11,14 +11,16 @@ import subprocess
 import sys
 import textwrap
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax._src.dispatch import BACKEND_COMPILE_EVENT
 
 from repro.configs import get_reduced
 from repro.configs.base import scaled
 from repro.core import index as IX
-from repro.serve import QueryLoad, ServeSession
+from repro.serve import QueryBatch, QueryLoad, ServeSession
 
 CFG = scaled(get_reduced("webparf"),
              kernel_impl=os.environ.get("REPRO_KERNEL_IMPL", "auto"),
@@ -139,6 +141,47 @@ def test_report_shapes_and_percentiles():
     for key in ("qps", "p50_ms", "p99_ms", "freshness_lag_steps",
                 "index_docs", "index_dropped", f"recall_at_{K}"):
         assert key in m, m
+
+
+class _SizedLoad:
+    """A load whose takes hand over a set number of queries each."""
+
+    def __init__(self, sizes, n_domains):
+        self.sizes, self.n_domains = list(sizes), n_domains
+
+    def take(self, cursor, t_now):
+        n = self.sizes.pop(0) if self.sizes else 0
+        rng = np.random.default_rng(cursor)
+        return QueryBatch(
+            time=np.full(n, t_now - 0.5),
+            domain=rng.integers(0, self.n_domains, n).astype(np.int32),
+            seed=rng.integers(1, 1 << 31, n).astype(np.uint32),
+            cursor=cursor + n)
+
+
+def test_partial_batches_compile_nothing():
+    """After two intervals of full batches (the first query and fold see
+    the initial index's placement, the second the folded one's), intervals
+    that end in a partial batch of any size compile nothing: the answers
+    are cut to size on the host, so no compile lands in a serving window."""
+    B = 4
+    sizes = [B, B, 1, B + 2, 2 * B + 3]
+    sess = make_sess(query_batch=B)
+    sess.load = _SizedLoad(sizes, CFG.n_domains)
+    sess.run(2 * IV, recall=False)             # compiles, full batches only
+    compiles = []
+
+    def listen(event, secs, **kw):
+        if event == BACKEND_COMPILE_EVENT:
+            compiles.append(secs)
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        rep = sess.run(3 * IV, recall=False)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
+    assert rep.top_urls.shape == (sum(sizes[2:]), K)
+    assert rep.top_scores.shape == (sum(sizes[2:]), K)
 
 
 # ---------------------------------------------------------------------------
