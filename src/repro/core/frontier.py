@@ -176,25 +176,16 @@ def select_harvest(f: Frontier, table: jax.Array, k: int, *,
             idx, cash, table2)
 
 
-def _plan_insert(f: Frontier, urls: jax.Array, scores: jax.Array,
-                 mask: jax.Array, *, n_buckets: int):
-    """Shared insert core: FIFO rebase, priority encoding, and free-slot
-    targeting. Returns (rebased frontier, pri, fits, tgt_safe, incoming)
-    where ``tgt_safe`` (R, M) is each item's destination column (C for
-    dropped items — the trash column)."""
-    R, C = f.url.shape
-    incoming = mask.sum(axis=1).astype(jnp.int32)                   # (R,)
-    f = _rebase_fifo(f, incoming)
-    # FIFO arrival sequence for the incoming batch
-    order = jnp.cumsum(mask.astype(jnp.int32), axis=1) - 1          # (R, M)
-    pri = encode_priority(scores, f.arrival[:, None] + order, n_buckets)
-
-    # free slots: the o-th incoming item goes to the o-th invalid slot (in
-    # column order). Instead of a full (R, C) argsort (XLA lowers sort at
-    # O(C log C) per row), scatter each free slot's column index at its rank
-    # among free slots — ranks are unique per row, so the scatter is
-    # collision-free, and the whole mapping is O(C)
-    free = ~f.valid
+def _free_slots_scatter(valid: jax.Array, mask: jax.Array,
+                        order: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """General free-slot targeting: the o-th incoming item goes to the o-th
+    invalid slot (in column order). Each free slot's column index is
+    scattered at its rank among free slots — ranks are unique per row, so
+    the scatter is collision-free. On the v5e that scatter serialises its
+    R*C updates (about 18 ms at 512 x 4096), so M = 1 takes
+    ``_first_free_slot`` instead. Returns (tgt_safe, fits), both (R, M)."""
+    R, C = valid.shape
+    free = ~valid
     rank = jnp.cumsum(free.astype(jnp.int32), axis=1) - free        # exclusive
     n_free = free.sum(axis=1)                                       # (R,)
     rows = jnp.arange(R)[:, None]
@@ -207,7 +198,45 @@ def _plan_insert(f: Frontier, urls: jax.Array, scores: jax.Array,
     # dropped items scatter into a trash column (index C) so they can never
     # collide with a legitimate write — duplicate-index scatter order is
     # undefined in XLA, so collisions must be structurally impossible
-    tgt_safe = jnp.where(fits, tgt, C)
+    return jnp.where(fits, tgt, C), fits
+
+
+def _first_free_slot(valid: jax.Array, mask: jax.Array
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """Free-slot targeting for one incoming item a row (M = 1): the item's
+    slot is the row's first free column, one min-reduction over the grid.
+    Same (tgt_safe, fits) as ``_free_slots_scatter``; C is the trash
+    column of a dropped item."""
+    C = valid.shape[1]
+    first = jnp.min(jnp.where(valid, C, jnp.arange(C, dtype=jnp.int32)),
+                    axis=1, keepdims=True)                          # (R, 1)
+    fits = mask & (first < C)
+    return jnp.where(fits, first, C), fits
+
+
+def _free_slots(valid: jax.Array, mask: jax.Array, order: jax.Array
+                ) -> Tuple[jax.Array, jax.Array]:
+    """Each incoming item's destination column: (tgt_safe, fits), (R, M).
+    The form follows the static width M of the incoming batch."""
+    if mask.shape[1] == 1:
+        return _first_free_slot(valid, mask)
+    return _free_slots_scatter(valid, mask, order)
+
+
+def _plan_insert(f: Frontier, urls: jax.Array, scores: jax.Array,
+                 mask: jax.Array, *, n_buckets: int):
+    """Shared insert core: FIFO rebase, priority encoding, and free-slot
+    targeting. Returns (rebased frontier, pri, fits, tgt_safe, incoming)
+    where ``tgt_safe`` (R, M) is each item's destination column (C for
+    dropped items — the trash column)."""
+    incoming = mask.sum(axis=1).astype(jnp.int32)                   # (R,)
+    f = _rebase_fifo(f, incoming)
+    # FIFO arrival sequence for the incoming batch
+    order = jnp.cumsum(mask.astype(jnp.int32), axis=1) - 1          # (R, M)
+    pri = encode_priority(scores, f.arrival[:, None] + order, n_buckets)
+
+    with jax.named_scope("frontier/free_slots"):
+        tgt_safe, fits = _free_slots(f.valid, mask, order)
     return f, pri, fits, tgt_safe, incoming
 
 

@@ -1,4 +1,8 @@
 """Frontier invariants — unit + hypothesis property tests."""
+import math
+import re
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -175,3 +179,119 @@ def test_multi_row_independence():
     got, _, mask, _ = F.select(f, 1)
     assert list(np.asarray(got)[:, 0]) == [1, 2, 3]
     assert mask.all()
+
+
+# ---------------------------------------------------------------------------
+# free-slot targeting: the M = 1 reduction against the general scatter
+# ---------------------------------------------------------------------------
+
+def _grid(case, R=64, C=512, seed=0):
+    """(valid (R, C), mask (R, 1)) for one fill pattern of the grid."""
+    rng = np.random.default_rng(seed)
+    mask = rng.random((R, 1)) < 0.8
+    if case == "empty":
+        valid = np.zeros((R, C), bool)
+    elif case == "full":
+        valid = np.ones((R, C), bool)
+    elif case == "last_free":
+        valid = np.ones((R, C), bool)
+        valid[:, -1] = False
+    elif case == "mask_off":
+        valid = rng.random((R, C)) < 0.5
+        mask[:] = False
+    else:                                   # "fill_<share>"
+        valid = rng.random((R, C)) < float(case.split("_")[1])
+        valid[0] = True                     # one full row
+        valid[1] = True
+        valid[1, -1] = False                # one row free at C - 1 alone
+    return jnp.asarray(valid), jnp.asarray(mask)
+
+
+def _filled(valid, seed=0):
+    """A frontier and url-lane table holding ``valid`` (the lane invariant:
+    invalid cells hold exactly 0)."""
+    rng = np.random.default_rng(seed + 1)
+    R, C = valid.shape
+    f = F.init_frontier(R, C)
+    pri = F.encode_priority(jnp.asarray(rng.random((R, C)), jnp.float32),
+                            jnp.asarray(rng.integers(0, 1000, (R, C))), 8)
+    f = f._replace(valid=valid, priority=jnp.where(valid, pri, F.NEG),
+                   url=jnp.where(valid, jnp.asarray(
+                       rng.integers(1, 1 << 30, (R, C)), jnp.uint32), 0),
+                   arrival=jnp.full((R,), 1000, jnp.int32))
+    table = jnp.where(valid, jnp.asarray(rng.random((R, C)), jnp.float32), 0.0)
+    return f, table
+
+
+FILL_CASES = ["empty", "full", "last_free", "mask_off",
+              "fill_0.1", "fill_0.9", "fill_0.999"]
+
+
+@pytest.mark.parametrize("case", FILL_CASES)
+def test_first_free_slot_matches_scatter_targets(case):
+    """One item a row: the row's first free column is the general path's
+    target for order 0, and the drops agree, bit for bit."""
+    valid, mask = _grid(case)
+    order = jnp.cumsum(mask.astype(jnp.int32), axis=1) - 1
+    want_tgt, want_fits = F._free_slots_scatter(valid, mask, order)
+    tgt, fits = F._first_free_slot(valid, mask)
+    np.testing.assert_array_equal(np.asarray(tgt), np.asarray(want_tgt))
+    np.testing.assert_array_equal(np.asarray(fits), np.asarray(want_fits))
+    got_tgt, got_fits = F._free_slots(valid, mask, order)
+    np.testing.assert_array_equal(np.asarray(got_tgt), np.asarray(tgt))
+    np.testing.assert_array_equal(np.asarray(got_fits), np.asarray(fits))
+
+
+@pytest.mark.parametrize("case", FILL_CASES)
+def test_insert_valued_m1_matches_scatter_path(case, monkeypatch):
+    """The whole value-carrying insert of one item a row (the allocator's
+    give-back) is the same frontier, table and refund on either path."""
+    valid, mask = _grid(case)
+    f, table = _filled(valid)
+    R = valid.shape[0]
+    rng = np.random.default_rng(7)
+    urls = jnp.asarray(rng.integers(1 << 30, 1 << 31, (R, 1)), jnp.uint32)
+    scores = jnp.asarray(rng.random((R, 1)), jnp.float32)
+    vals = jnp.where(mask, jnp.asarray(rng.random((R, 1)), jnp.float32), 0.0)
+
+    def run():
+        return F.insert_valued(f, table, urls, scores, mask, vals,
+                               n_buckets=8)
+
+    got = run()
+    monkeypatch.setattr(F, "_free_slots",
+                        lambda v, m, o: F._free_slots_scatter(v, m, o))
+    want = run()
+    for name, a, b in zip(F.Frontier._fields, got[0], want[0]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=f"Frontier.{name}")
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    np.testing.assert_array_equal(np.asarray(got[2]), np.asarray(want[2]))
+
+
+def _scatter_update_sizes(hlo: str) -> list:
+    """Element counts of the updates operand of every scatter in ``hlo``
+    (HLO text, as ``Lowered.as_text(dialect="hlo")`` prints it)."""
+    shape = dict(re.findall(r"([\w.\-]+) = \w+\[([\d,]*)\]", hlo))
+    sizes = []
+    for m in re.finditer(r" scatter\(([^)]*)\)", hlo):
+        upd = m.group(1).split(", ")[-1]
+        sizes.append(math.prod(int(d) for d in shape[upd].split(",") if d))
+    return sizes
+
+
+@pytest.mark.parametrize("M", [1, 4])
+def test_m1_insert_lowers_without_grid_scatter(M):
+    """An (R, C) = (64, 512) insert of one item a row lowers with no
+    scatter whose updates span the grid; a wider batch keeps the general
+    path's rank scatter of R * C updates."""
+    R, C = 64, 512
+    f, table = _filled(jnp.zeros((R, C), bool))
+    args = (jnp.zeros((R, M), jnp.uint32), jnp.zeros((R, M), jnp.float32),
+            jnp.zeros((R, M), bool), jnp.zeros((R, M), jnp.float32))
+    hlo = jax.jit(lambda f, t, u, s, m, v: F.insert_valued(
+        f, t, u, s, m, v, n_buckets=8)).lower(f, table, *args).as_text(
+            dialect="hlo")
+    sizes = _scatter_update_sizes(hlo)
+    assert sizes, "no scatter found in the insert"
+    assert (R * C in sizes) == (M > 1), sizes

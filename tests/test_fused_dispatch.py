@@ -18,6 +18,7 @@ import jax
 import numpy as np
 import pytest
 
+from repro.api import CrawlSession
 from repro.configs import get_reduced
 from repro.configs.base import scaled
 from repro.core import crawler as CR
@@ -106,3 +107,38 @@ def test_fused_flag_is_noop_without_url_lane(base_cfg):
     on = crawl_trajectory(scaled(cfg, fused_dispatch=True), steps)
     off = crawl_trajectory(scaled(cfg, fused_dispatch=False), steps)
     assert_trajectories_equal(on, off, "no-url-lane")
+
+
+def chunk_trajectory(cfg, chunks, *, fail_at=None):
+    """The state after each fused chunk (``chunk_program``: the scanned
+    fetch steps, then the dispatch step) and the chunk's reports; shard 0
+    dies before chunk ``fail_at``, so from then on every pop goes back."""
+    sess = CrawlSession(cfg)
+    out = []
+    for c in range(chunks):
+        if c == fail_at:
+            sess.inject_failure(0)
+        rep = sess.run_chunk()
+        out.append((jax.device_get(sess.state), jax.device_get(rep)))
+    return out
+
+
+@pytest.mark.parametrize("fetch_batch,k_row", [(8, 1), (32, 2)])
+def test_chunk_pins_hold_for_give_back_width(base_cfg, fetch_batch, k_row):
+    """The allocator's give-backs insert k_row items a row: k_row = 1 (rows
+    >= fetch_batch, as in deployment A) targets each row's first free
+    column by a reduction, k_row > 1 keeps the rank scatter. Either way the
+    fused chunk matches the unfused one, and interpret matches ref, over a
+    live chunk (over-budget give-back) and a dead one (all pops back)."""
+    cfg = scaled(base_cfg, fetch_batch=fetch_batch, kernel_impl="ref")
+    ctx = ST.make_context(cfg, n_shards=1, axes=("data",),
+                          classify_accuracy=1.0)
+    assert ctx.k_row == k_row
+    fused = chunk_trajectory(scaled(cfg, fused_dispatch=True), 2, fail_at=1)
+    plain = chunk_trajectory(scaled(cfg, fused_dispatch=False), 2, fail_at=1)
+    assert np.asarray(fused[1][0].stats)[:, ST.SIDX["revived"]].sum() > 0
+    assert_trajectories_equal(without_walk_counters(fused),
+                              without_walk_counters(plain), "fused<->unfused")
+    got = chunk_trajectory(scaled(cfg, fused_dispatch=True,
+                                  kernel_impl="interpret"), 2, fail_at=1)
+    assert_trajectories_equal(fused, got, "ref<->interpret")
